@@ -21,7 +21,12 @@ import json
 import os
 
 from repro.configs import SHAPES, get_config
-from repro.launch.mesh import PEAK_FLOPS_BF16, HBM_BW, ICI_BW
+from repro.launch.mesh import DEVICE_PEAKS
+
+# the dry run compiles for the production mesh of TPU v5e chips
+_V5E = DEVICE_PEAKS["TPU v5 lite"]
+PEAK_FLOPS_BF16, HBM_BW, ICI_BW = (_V5E["flops_bf16"], _V5E["hbm_bw"],
+                                   _V5E["ici_bw"])
 
 RESULTS = os.path.join(os.path.dirname(__file__), "results", "dryrun")
 

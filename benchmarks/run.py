@@ -40,6 +40,8 @@ def main() -> None:
                                        "fleet", "sched"],
                     default=None)
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     print("name,value,derived")
 
     t0 = time.time()

@@ -5,15 +5,14 @@ Each kernel package contains:
   ops.py    — jit'd public wrapper (padding, layout, dtype policy)
   ref.py    — pure-jnp oracle used by the allclose test sweeps
 
-On this CPU container kernels are validated with interpret=True; the
-XLA paths in models/ and core/ are the default execution route (see
-DESIGN.md §7 — hardware-adaptation notes).
-
-``INTERPRET`` used to be a hand-flipped constant; it is now resolved at
-import from the active jax backend (compiled Pallas on real TPUs,
-interpret everywhere Mosaic cannot lower).  Per-entry-point overrides —
-including falling back to the XLA oracle in ``ref.py`` when the compiled
-kernel loses or fails to lower — come from ``runtime/autotune.py``.
+``INTERPRET`` is resolved at import from the active jax backend: compiled
+Pallas (Mosaic) on a TPU, interpret mode everywhere else, which is how the
+CPU test suite checks the kernels against ``ref.py``.  ``seafl_agg`` is
+the server hot path and runs compiled on the chip (``chip_smoke.py``;
+``tests/test_tpu_compile.py`` compiles it for a described v5e).  The
+models in ``models/`` call no kernel yet: their attention, recurrence and
+scan run as XLA.  Per-entry-point ``block_p`` and oracle routing come from
+``runtime/autotune.py``.
 """
 
 from repro.runtime.autotune import resolve_interpret

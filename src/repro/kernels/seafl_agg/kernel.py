@@ -31,7 +31,7 @@ def _sim_kernel(d_ref, g_ref, out_ref):
     i = pl.program_id(0)
     d = d_ref[...].astype(jnp.float32)
     g = g_ref[0].astype(jnp.float32)
-    dot = d @ g                                # (K,)
+    dot = jnp.sum(d * g[None, :], axis=1)      # (K,)
     dsq = jnp.sum(d * d, axis=1)               # (K,)
     gsq = jnp.broadcast_to(jnp.sum(g * g), dot.shape)
     part = jnp.stack([dot, dsq, gsq, jnp.zeros_like(dot)], axis=1)  # (K,4)
@@ -77,7 +77,7 @@ def _sim_from_params_kernel(w_ref, g_ref, out_ref):
     w = w_ref[...].astype(jnp.float32)
     g = g_ref[0].astype(jnp.float32)
     d = w - g[None, :]
-    dot = d @ g                                # (K,)  Delta_k . w_g
+    dot = jnp.sum(d * g[None, :], axis=1)      # (K,)  Delta_k . w_g
     dsq = jnp.sum(d * d, axis=1)               # (K,)  ||Delta_k||^2
     gsq = jnp.broadcast_to(jnp.sum(g * g), dot.shape)
     part = jnp.stack([dot, dsq, gsq, jnp.zeros_like(dot)], axis=1)  # (K,4)
@@ -111,33 +111,51 @@ def similarity_partials_from_params_call(params, global_flat, block_p=2048,
     )(params, global_flat[None, :])
 
 
-def _agg_kernel(w_ref, theta_ref, p_ref, g_ref, out_ref):
-    """Grid (nP,).  w:(1,K) theta:(1,1) p:(K,BP) g:(1,BP) out:(1,BP)."""
-    w = w_ref[0].astype(jnp.float32)           # (K,)
-    theta = theta_ref[0, 0]
-    p = p_ref[...].astype(jnp.float32)         # (K, BP)
-    g = g_ref[0].astype(jnp.float32)           # (BP,)
-    out = (1.0 - theta) * g + theta * (w @ p)
-    out_ref[0] = out.astype(out_ref.dtype)
+def _agg_kernel(coef_ref, w_ref, p_ref, g_ref, out_ref):
+    """Grid (nP,).  coef:(2,) SMEM [a, b]  w:(K,1) p:(K,BP) g:(1,BP)
+    out:(1,BP) = a * g + b * sum_k w_k p_k.
+
+    The K-mix is a broadcast-multiply and a sublane sum on the VPU, in f32:
+    a (1,K)@(K,BP) product would fill one MXU row in K and Mosaic refuses
+    the 1-D (K,)@(K,BP) form outright."""
+    a = coef_ref[0]
+    b = coef_ref[1]
+    w = w_ref[...].astype(jnp.float32)                     # (K, 1)
+    p = p_ref[...].astype(jnp.float32)                     # (K, BP)
+    g = g_ref[...].astype(jnp.float32)                     # (1, BP)
+    mix = jnp.sum(w * p, axis=0, keepdims=True)            # (1, BP)
+    out_ref[...] = (a * g + b * mix).astype(out_ref.dtype)
 
 
 def weighted_agg_call(weights, stacked, global_flat, theta,
-                      block_p=2048, interpret=True):
-    """weights:(K,) stacked:(K,P) global:(P,) -> (P,) fused Eq.(7)+(8)."""
+                      block_p=2048, interpret=True, global_coef=None,
+                      out_dtype=None):
+    """weights:(K,) stacked:(K,P) global:(P,) -> (P,) fused Eq.(7)+(8):
+    ``(1 - theta) * global + theta * weights @ stacked``.
+
+    ``global_coef`` replaces the ``1 - theta`` on the global term (the
+    slot-sharded path gives it to one shard only, so a sum of the shards'
+    outputs counts the global once); ``out_dtype`` defaults to the
+    global's dtype."""
     K, P = stacked.shape
     grid = (P // block_p,)
-    theta_arr = jnp.asarray(theta, jnp.float32).reshape(1, 1)
+    theta = jnp.asarray(theta, jnp.float32)
+    a = 1.0 - theta if global_coef is None else jnp.asarray(global_coef,
+                                                             jnp.float32)
+    coef = jnp.stack([a, theta])
+    out_dtype = global_flat.dtype if out_dtype is None else out_dtype
     out = pl.pallas_call(
         _agg_kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, K), lambda i: (0, 0)),
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec((K, 1), lambda i: (0, 0)),
             pl.BlockSpec((K, block_p), lambda i: (0, i)),
             pl.BlockSpec((1, block_p), lambda i: (0, i)),
         ],
         out_specs=pl.BlockSpec((1, block_p), lambda i: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((1, P), global_flat.dtype),
+        out_shape=jax.ShapeDtypeStruct((1, P), out_dtype),
         interpret=interpret,
-    )(weights[None, :], theta_arr, stacked, global_flat[None, :])
+    )(coef, weights.astype(jnp.float32)[:, None], stacked,
+      global_flat[None, :])
     return out[0]

@@ -30,7 +30,19 @@ def make_mesh(shape, axes=None):
     return jax.make_mesh(shape, tuple(axes))
 
 
-# v5e hardware constants used by the roofline analysis (benchmarks/roofline).
-PEAK_FLOPS_BF16 = 197e12        # per chip
-HBM_BW = 819e9                  # bytes/s per chip
-ICI_BW = 50e9                   # bytes/s per link
+# Published per-chip peaks, keyed by ``jax.devices()[0].device_kind``.
+# TPU v5e: Google Cloud documentation, "TPU v5e" (system architecture):
+# 197 TFLOP/s bf16, 393 TOP/s int8, 16 GB HBM at 819 GB/s, 1,600 Gbit/s
+# of inter-chip interconnect (four links of 50 GB/s).  A device that is
+# not in the table has no peaks: callers report "no prediction" for it
+# instead of borrowing another chip's numbers.
+DEVICE_PEAKS = {
+    "TPU v5 lite": {
+        "flops_bf16": 197e12,
+        "ops_int8": 393e12,
+        "hbm_bytes": 16e9,
+        "hbm_bw": 819e9,          # bytes/s
+        "ici_bw": 50e9,           # bytes/s per link
+    },
+}
+

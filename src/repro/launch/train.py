@@ -7,12 +7,12 @@ adaptive Eq. (4)-(8) weights.  Client heterogeneity (the reason SEAFL
 exists) is injected by the same event timeline as simulation mode, while
 every update is genuine sharded JAX training.
 
-On this CPU container it drives the reduced (smoke) configs end-to-end —
-the same code path scales to the production mesh by passing --mesh.
+By default it builds the reduced (smoke) config of ``--arch``;
+``--no-smoke`` builds the published widths (``configs.get_config``).
 
 Usage:
-  PYTHONPATH=src python -m repro.launch.train --arch qwen3-32b --smoke \
-      --rounds 20 --clients 8 --buffer 4 [--algorithm seafl2]
+  PYTHONPATH=src python -m repro.launch.train --arch whisper-tiny \
+      --rounds 20 --clients 8 --buffer 4 [--no-smoke] [--algorithm seafl2]
 """
 from __future__ import annotations
 
@@ -30,8 +30,14 @@ from repro.configs import get_config, smoke_config
 from repro.core.client import Client
 from repro.core.server import FLConfig, SeaflServer
 from repro.data.synthetic import make_lm_dataset
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model
 from repro.runtime.simulator import FLSimulation, SimConfig
+
+
+def model_config(arch: str, smoke: bool):
+    """The reduced config of ``arch``, or its published widths."""
+    return smoke_config(arch) if smoke else get_config(arch)
 
 
 def build_lm_fl(arch: str, *, smoke: bool = True, n_clients: int = 8,
@@ -51,7 +57,7 @@ def build_lm_fl(arch: str, *, smoke: bool = True, n_clients: int = 8,
                 telemetry: bool = False, telemetry_kernels: bool = False,
                 monitor: str = "off", slo=None, monitor_byte_budget=None,
                 scheduler: str = "random", autotune: str = "off"):
-    cfg = smoke_config(arch) if smoke else get_config(arch)
+    cfg = model_config(arch, smoke)
     model = build_model(cfg)
     params0 = model.init(jax.random.PRNGKey(seed))
 
@@ -111,11 +117,13 @@ def build_lm_fl(arch: str, *, smoke: bool = True, n_clients: int = 8,
     test = add_extras(dict(make_lm_dataset(cfg.vocab_size, seq_len, 16,
                                            seed=seed + 1)), 16, seed + 23)
     test_j = {k: jnp.asarray(v) for k, v in test.items()}
-    loss_jit = jax.jit(lambda p: loss_fn(p, test_j)[0])
+    # the held-out batch is an argument, not a closure: a closed-over array
+    # would be compiled into the program as a constant
+    loss_jit = jax.jit(lambda p, batch: loss_fn(p, batch)[0])
 
     def eval_fn(params):
         # report "accuracy" as negative loss so target_acc machinery works
-        return -float(loss_jit(params))
+        return -float(loss_jit(params, test_j))
 
     return model, server, clients, eval_fn
 
@@ -253,10 +261,20 @@ class JsonlLog:
             self._fh = None
 
 
-def main():
+def device_line() -> str:
+    """The device this process runs on, as JAX reports it."""
+    devs = jax.devices()
+    return (f"platform={devs[0].platform} device_kind={devs[0].device_kind} "
+            f"devices={len(devs)}")
+
+
+def parse_args(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="internvl2-1b")
-    ap.add_argument("--smoke", action="store_true", default=True)
+    ap.add_argument("--smoke", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="reduced config of --arch (default); --no-smoke "
+                         "builds its published widths")
     ap.add_argument("--algorithm", default="seafl",
                     choices=["seafl", "seafl2", "fedbuff", "fedasync",
                              "fedavg"])
@@ -366,11 +384,18 @@ def main():
                          "pin); 'cache' applies the user-cache / committed "
                          "default-table winners; 'sweep' measures this "
                          "run's shapes first and persists the winners")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
     if args.slo is not None:
         args.monitor = "on"
     if args.trace or args.metrics:
         args.telemetry = True
+    return args
+
+
+def main(argv=None):
+    args = parse_args(argv)
+    enable_compile_cache()
+    print(f"[train] {device_line()}", flush=True)
 
     model, server, clients, eval_fn = build_lm_fl(
         args.arch, smoke=args.smoke, n_clients=args.clients,

@@ -15,13 +15,16 @@ running.  This module makes the compute layer measurement-driven:
   * per-entry-point sweeps time every ``block_p`` candidate *and* the
     XLA-oracle twin (``kernels/seafl_agg/ref.py``) with the same
     block-until-ready clock the ``set_kernel_timing`` histograms use, so a
-    backend where the compiled kernel loses (or fails to lower) is routed
-    to the oracle per entry point, never process-wide;
+    backend where the compiled kernel loses is routed to the oracle per
+    entry point, never process-wide (on a TPU, a kernel that fails to lower
+    at the default ``block_p`` raises instead);
 
   * each measurement is cross-checked against the analytical roofline
-    (``benchmarks/roofline.py`` constants + ``launch/hlo_cost.py`` HLO
-    parsing): every sweep reports measured-vs-predicted so a config that
-    "wins" at 40x the roofline bound is visibly suspicious;
+    (the running device's entry in ``launch/mesh.DEVICE_PEAKS`` +
+    ``launch/hlo_cost.py`` HLO parsing): every sweep reports
+    measured-vs-predicted so a config that "wins" at 40x the roofline
+    bound is visibly suspicious; a device without peaks gets no
+    prediction;
 
   * winning configs are cached in a versioned JSON keyed by ``(jax device
     kind, dtype, scheme, P-bucket, K-bucket)`` — under ``~/.cache`` for
@@ -105,11 +108,10 @@ _DEFAULT_TABLE = os.path.join(os.path.dirname(os.path.abspath(__file__)),
 # ------------------------------------------------------------ chip identity
 
 def device_kind() -> str:
-    """`jax.devices()[0].device_kind` — the cache's per-chip axis."""
-    try:
-        return str(jax.devices()[0].device_kind)
-    except Exception:                                  # pragma: no cover
-        return "unknown"
+    """`jax.devices()[0].device_kind` — the cache's per-chip axis.  A
+    backend that fails to start raises here rather than keying tuning
+    entries to a made-up device."""
+    return str(jax.devices()[0].device_kind)
 
 
 def resolve_interpret(backend: Optional[str] = None) -> bool:
@@ -294,16 +296,26 @@ def _make_timer(timer=None, telemetry=None, reps: int = 3):
 
 # ------------------------------------------------------------- prediction
 
-def _roofline_constants():
-    from repro.launch.mesh import HBM_BW, PEAK_FLOPS_BF16
-    return PEAK_FLOPS_BF16, HBM_BW
+def _roofline_constants(kind: Optional[str] = None):
+    """(peak bf16 FLOP/s, HBM bytes/s) of the running device (or ``kind``),
+    None for a device the peak table does not list."""
+    from repro.launch.mesh import DEVICE_PEAKS
+    peaks = DEVICE_PEAKS.get(kind if kind is not None else device_kind())
+    if peaks is None:
+        return None
+    return peaks["flops_bf16"], peaks["hbm_bw"]
 
 
-def predict_agg_seconds(entry: str, p: int, k: int, dtype) -> float:
+def predict_agg_seconds(entry: str, p: int, k: int, dtype,
+                        kind: Optional[str] = None) -> Optional[float]:
     """Analytical roofline bound for one entry point (seconds on the
-    production chip): max(memory, compute) with the ``benchmarks/roofline``
-    convention of 2x materialised bytes over HBM bandwidth."""
-    peak, hbm_bw = _roofline_constants()
+    running chip, or on ``kind``): max(memory, compute) with the
+    ``benchmarks/roofline`` convention of 2x materialised bytes over HBM
+    bandwidth.  None when the device has no entry in the peak table."""
+    consts = _roofline_constants(kind)
+    if consts is None:
+        return None
+    peak, hbm_bw = consts
     item = jnp.dtype(dtype).itemsize
     if entry == "weighted_aggregate":
         bytes_ = (k * p + p) * item + p * item          # read K+1, write 1
@@ -320,12 +332,16 @@ def predict_agg_seconds(entry: str, p: int, k: int, dtype) -> float:
 def predict_from_hlo(fn: Callable, *args) -> Optional[float]:
     """Cross-check: compile the XLA path and run the trip-count-aware HLO
     cost model (``launch/hlo_cost.py``) through the same roofline terms.
-    None when the backend will not hand back compiled HLO text."""
+    None for a device without peaks, or when the backend will not hand
+    back compiled HLO text."""
+    consts = _roofline_constants()
+    if consts is None:
+        return None
+    peak, hbm_bw = consts
     try:
         hlo = jax.jit(fn).lower(*args).compile().as_text()
         from repro.launch.hlo_cost import analyze_hlo
         cost = analyze_hlo(hlo)
-        peak, hbm_bw = _roofline_constants()
         t = max(2.0 * cost.get("hbm_bytes", 0.0) / hbm_bw,
                 cost.get("flops", 0.0) / peak)
         return t if t > 0 else None
@@ -397,7 +413,8 @@ def sweep_agg_entry(entry: str, p: int, k: int, dtype="float32", *,
     Deterministic given ``timer`` (a ``timer(fn, label) -> seconds``
     injectable; the default is the block-until-ready wall clock).  A
     candidate that fails to lower is recorded as ``inf`` and can never
-    win — which is exactly the per-entry-point oracle fallback story."""
+    win — except the default ``block_p`` on a TPU backend, whose failure
+    is a broken kernel and raises instead of letting the oracle win."""
     if entry not in AGG_ENTRY_POINTS:
         raise ValueError(f"unknown agg entry point {entry!r} "
                          f"(expected one of {AGG_ENTRY_POINTS})")
@@ -410,6 +427,8 @@ def sweep_agg_entry(entry: str, p: int, k: int, dtype="float32", *,
                 _agg_call(entry, inputs, block_p=bp, interpret=interpret),
                 (entry, "block_p", int(bp))))
         except Exception:
+            if bp == DEFAULT_BLOCK_P and jax.default_backend() == "tpu":
+                raise
             cand_s[int(bp)] = float("inf")   # failed to lower: cannot win
     try:
         oracle_s = float(clock(_agg_call(entry, inputs, oracle=True),
@@ -421,9 +440,10 @@ def sweep_agg_entry(entry: str, p: int, k: int, dtype="float32", *,
     use_oracle = oracle_s < best_s
     tuned_s = oracle_s if use_oracle else best_s
     predicted = predict_agg_seconds(entry, int(p), int(k), dtype)
-    hlo_pred = predict_from_hlo(_agg_call(entry, inputs, oracle=True))
-    if hlo_pred is not None:
-        predicted = max(predicted, hlo_pred)
+    if predicted is not None:
+        hlo_pred = predict_from_hlo(_agg_call(entry, inputs, oracle=True))
+        if hlo_pred is not None:
+            predicted = max(predicted, hlo_pred)
     default_s = cand_s[DEFAULT_BLOCK_P]
     return {
         "kind": "agg", "entry": entry, "p": int(p), "k": int(k),
@@ -434,9 +454,10 @@ def sweep_agg_entry(entry: str, p: int, k: int, dtype="float32", *,
         "oracle_us": round(oracle_s * 1e6, 3),
         "candidates_us": {str(b): round(s * 1e6, 3)
                           for b, s in sorted(cand_s.items())},
-        "predicted_us": round(predicted * 1e6, 3),
+        "predicted_us": (round(predicted * 1e6, 3)
+                         if predicted is not None else None),
         "measured_vs_predicted": round(tuned_s / predicted, 3)
-        if predicted > 0 else None,
+        if predicted else None,
     }
 
 
