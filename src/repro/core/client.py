@@ -15,6 +15,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.runtime.telemetry import NULL
+
 PyTree = Any
 
 
@@ -65,9 +67,14 @@ class Client:
         return jax.tree.map(lambda a: a[idx], self.data)
 
     def local_train(self, params: PyTree, n_epochs: int, lr: float):
-        """Run n_epochs of SGD; returns (new_params, mean_loss)."""
-        loss = jnp.float32(0.0)
-        for _ in range(max(1, n_epochs)):
-            batches = self._epoch_batches()
-            params, loss = self.epoch_fn(params, batches, lr)
-        return params, float(loss)
+        """Run n_epochs of SGD; returns (new_params, mean_loss).  The
+        ``client.train`` span's own time is the wait for the last epoch's
+        loss."""
+        with NULL.span("client.train"):
+            loss = jnp.float32(0.0)
+            for _ in range(max(1, n_epochs)):
+                with NULL.span("client.batches"):
+                    batches = self._epoch_batches()
+                with NULL.span("client.epoch"):
+                    params, loss = self.epoch_fn(params, batches, lr)
+            return params, float(loss)

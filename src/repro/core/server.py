@@ -138,10 +138,6 @@ class FLConfig:
     # pinned zero behavioral change (RNG stream, wire bytes, aggregation
     # outputs bit-identical — the cohorts='off' discipline).
     telemetry: bool = False
-    # opt-in kernel wall timings: block_until_ready around each seafl_agg
-    # aggregate call and each codec encode/decode (changes device-dispatch
-    # overlap, never values) — the same clock the autotuner sweeps with
-    telemetry_kernels: bool = False
     # run-health monitor (runtime/monitor.py): 'on' runs the online
     # anomaly detectors (plateau, staleness blowup, straggler dominance,
     # resync storms, ...) against every round record and attaches typed
@@ -285,11 +281,6 @@ class SeaflServer:
                                    dtype=self._buffer_dtype,
                                    telemetry=self.tel)
         self._batcher = self._make_batcher()
-        if self.tel.enabled and cfg.telemetry_kernels:
-            from repro.kernels.seafl_agg.ops import set_kernel_timing
-            from repro.runtime.codecs import set_codec_timing
-            set_kernel_timing(self.tel)
-            set_codec_timing(self.tel)
         # two-tier edge aggregation (cohorts='on'): same-version uploads
         # pre-combine into one resident (P,) partial per version, so the
         # buffer holds O(live versions) slots regardless of how many
@@ -521,12 +512,14 @@ class SeaflServer:
         """The model ``cid`` actually holds (training-base boundary): the
         exact dispatch-version global in legacy/f32 mode, the delivered
         reconstruction under lossy dispatch.  Unpacked once, here."""
-        if self.dispatch is None or cid not in self.dispatch.versions:
-            return self.params_at(self.active[cid])
-        held = self.dispatch.held_flat(cid, self._history)
-        if held is self._history.get(self.dispatch.versions[cid]):
-            return self.params_at(self.dispatch.versions[cid])   # f32: cached
-        return self.packer.unpack(held)
+        with self.tel.span("server.dispatch_model"):
+            if self.dispatch is None or cid not in self.dispatch.versions:
+                return self.params_at(self.active[cid])
+            held = self.dispatch.held_flat(cid, self._history)
+            if held is self._history.get(self.dispatch.versions[cid]):
+                # f32: cached
+                return self.params_at(self.dispatch.versions[cid])
+            return self.packer.unpack(held)
 
     # ------------------------------------------------------- uplink transport
     def encode_update(self, cid: int, client_params: PyTree,
@@ -536,31 +529,33 @@ class SeaflServer:
         delta-coded schemes (topk/int8) the delta is taken vs the dispatch
         version and the client's flat error-feedback residual is folded in
         and updated — per-leaf delta pytrees are never built."""
-        version = self.active[cid]
-        flat = self.packer.pack(client_params)
-        wire = self.wire
-        if wire.scheme == "topk":
-            if self.cfg.uplink_ratio_policy == "drift":
-                # the drift band chosen for the version this client trained
-                # from also sizes its upload (same discrete-ratio set)
-                r = self._ratio_by_version.get(version)
-                if r is not None:
-                    wire = dc_replace(wire, topk_ratio=r)
-            if n_epochs < self.cfg.local_epochs:
-                # SEAFL² byte coupling: a notified partial-training client
-                # did n' < E epochs of work, so its update carries
-                # proportionally less signal — ship proportionally fewer
-                # bytes.  (Decode is ratio-free: topk chunks carry their
-                # own indices.)
-                wire = dc_replace(
-                    wire, topk_ratio=wire.topk_ratio
-                    * max(1, n_epochs) / self.cfg.local_epochs)
-        base = ef = None
-        if wire.delta_coded:
-            base = self._uplink_base(cid, version)
-            ef = self._ef.setdefault(cid, FlatErrorFeedback())
-        return transport_encode_update(cid, version, n_epochs, flat,
-                                       wire, base, ef)
+        with self.tel.span("server.encode_update"):
+            version = self.active[cid]
+            flat = self.packer.pack(client_params)
+            wire = self.wire
+            if wire.scheme == "topk":
+                if self.cfg.uplink_ratio_policy == "drift":
+                    # the drift band chosen for the version this client
+                    # trained from also sizes its upload (same discrete-
+                    # ratio set)
+                    r = self._ratio_by_version.get(version)
+                    if r is not None:
+                        wire = dc_replace(wire, topk_ratio=r)
+                if n_epochs < self.cfg.local_epochs:
+                    # SEAFL² byte coupling: a notified partial-training
+                    # client did n' < E epochs of work, so its update
+                    # carries proportionally less signal — ship
+                    # proportionally fewer bytes.  (Decode is ratio-free:
+                    # topk chunks carry their own indices.)
+                    wire = dc_replace(
+                        wire, topk_ratio=wire.topk_ratio
+                        * max(1, n_epochs) / self.cfg.local_epochs)
+            base = ef = None
+            if wire.delta_coded:
+                base = self._uplink_base(cid, version)
+                ef = self._ef.setdefault(cid, FlatErrorFeedback())
+            return transport_encode_update(cid, version, n_epochs, flat,
+                                           wire, base, ef)
 
     def _uplink_base(self, cid: int, version: int) -> jnp.ndarray:
         """The flat base a delta-coded upload is measured against.
@@ -592,7 +587,7 @@ class SeaflServer:
             n_epochs=n_epochs, recv_time=recv_time))
         sess = IngestSession(self.buffer, slot, self.wire, base,
                              param_size=self.packer.size,
-                             batcher=self._batcher)
+                             batcher=self._batcher, telemetry=self.tel)
         self._ingests[cid] = sess
         return sess
 
@@ -619,28 +614,29 @@ class SeaflServer:
         (the driver may deliver the missing chunks or ``abort_ingest``).
         Concurrent streams may finish in any order; uploads still mid-stream
         keep their reserved rows across an aggregation's drain."""
-        sess = self._ingests[cid]
-        nbytes = sess.finish()           # raises while coverage is incomplete
-        del self._ingests[cid]
-        self.bytes_uploaded += nbytes
-        self.tel.counter("ingest.commits")
-        self.tel.histogram("ingest.upload_bytes", nbytes)
-        if self._batcher is not None:
-            # readers only ever see flushed rows: the slot's queued writes
-            # (and any co-batched neighbours) land before the commit
-            self._batcher.flush()
-        self.buffer.commit(sess.slot)
-        self._updates_since_agg += 1
-        if self._cohorts_on and self.buffer.capacity > 1:
-            self._edge_absorb(sess.slot)
-        self.active.pop(cid, None)
-        self.idle.add(cid)
-        filled = (self._updates_since_agg if self._cohorts_on
-                  else len(self.buffer))
-        if (filled >= self.buffer.capacity
-                and not self._blocked_by_stale()):
-            return self._aggregate(recv_time)
-        return None
+        with self.tel.span("ingest.commit"):
+            sess = self._ingests[cid]
+            nbytes = sess.finish()       # raises while coverage is incomplete
+            del self._ingests[cid]
+            self.bytes_uploaded += nbytes
+            self.tel.counter("ingest.commits")
+            self.tel.histogram("ingest.upload_bytes", nbytes)
+            if self._batcher is not None:
+                # readers only ever see flushed rows: the slot's queued
+                # writes (and any co-batched neighbours) land before the
+                # commit
+                self._batcher.flush()
+            self.buffer.commit(sess.slot)
+            self._updates_since_agg += 1
+            if self._cohorts_on and self.buffer.capacity > 1:
+                self._edge_absorb(sess.slot)
+            self.active.pop(cid, None)
+            self.idle.add(cid)
+            filled = (self._updates_since_agg if self._cohorts_on
+                      else len(self.buffer))
+            trigger = (filled >= self.buffer.capacity
+                       and not self._blocked_by_stale())
+        return self._aggregate(recv_time) if trigger else None
 
     def _edge_absorb(self, slot: int) -> None:
         """Two-tier aggregation, edge tier: fold the just-committed upload
@@ -682,10 +678,11 @@ class SeaflServer:
         chunks are adjacent windows of one slot, so they coalesce into a
         single donated dynamic-update (``IngestSession.write_all``) instead
         of one dispatch per chunk."""
-        sess = self.begin_ingest(payload.cid, payload.version,
-                                 payload.n_epochs, recv_time=recv_time)
-        sess.write_all(payload.chunks)
-        return self.finish_ingest(payload.cid, recv_time)
+        with self.tel.span("ingest"):
+            sess = self.begin_ingest(payload.cid, payload.version,
+                                     payload.n_epochs, recv_time=recv_time)
+            sess.write_all(payload.chunks)
+            return self.finish_ingest(payload.cid, recv_time)
 
     # ----------------------------------------------------------- on_update
     def on_update(self, cid: int, client_params: PyTree, n_epochs: int,

@@ -7,12 +7,6 @@ adaptive rule plus the baselines' weight rules, all expressed as one fused
 entry point (``seafl_aggregate_flat_from_params``) recovers the Eq. (5)
 cosine terms directly from client params, so no delta buffer ever exists.
 
-Kernel timing (opt-in): ``set_kernel_timing(telemetry)`` makes each public
-aggregate entry point block until its result is ready and record the wall
-time as a ``kernel.<name>_us`` histogram — the hook the per-chip autotuner
-builds on.  Off (the default) the entry points return un-synchronised like
-any jitted call: device overlap, values, and dtypes are untouched.
-
 Tuned routing (opt-in): each public wrapper accepts ``tuned=`` — a plan
 dict from ``runtime/autotune.py`` (``{'use_oracle': bool, 'block_p':
 int}``).  ``use_oracle`` dispatches to a jitted XLA twin built on
@@ -32,7 +26,6 @@ jitted bodies as the static ``slot_sharding``.
 """
 from __future__ import annotations
 
-import time
 from functools import partial
 from typing import Optional
 
@@ -49,30 +42,6 @@ from repro.kernels.seafl_agg.kernel import (
     similarity_partials_call, similarity_partials_from_params_call,
     weighted_agg_call,
 )
-
-# Opt-in kernel wall timing (FLConfig.telemetry_kernels): when set to an
-# enabled Telemetry, the public aggregate entry points block_until_ready
-# and record wall-time histograms.  None / disabled = plain jit dispatch.
-_KERNEL_TEL = None
-
-
-def set_kernel_timing(telemetry: Optional[object]) -> None:
-    """Install (or clear, with None) the Telemetry that times the public
-    aggregate entry points.  Process-wide by design: the opt-in flag is a
-    measurement mode, not protocol state."""
-    global _KERNEL_TEL
-    _KERNEL_TEL = telemetry
-
-
-def _timed(name: str, fn, *args, **kw):
-    tel = _KERNEL_TEL
-    if tel is None or not getattr(tel, "enabled", False):
-        return fn(*args, **kw)
-    t0 = time.perf_counter()
-    out = jax.block_until_ready(fn(*args, **kw))
-    tel.histogram(f"kernel.{name}_us", (time.perf_counter() - t0) * 1e6)
-    return out
-
 
 def slot_sharding_of(stacked) -> Optional[NamedSharding]:
     """The placement of a (K, P) buffer whose slot axis is split over mesh
@@ -92,7 +61,7 @@ def slot_sharding_of(stacked) -> Optional[NamedSharding]:
     return NamedSharding(sh.mesh, PartitionSpec(spec[0], None))
 
 
-def _route(name: str, jit_body, oracle_body, *args, **kw):
+def _route(jit_body, oracle_body, *args, **kw):
     """Dispatch one public entry point through its tuning plan.
 
     ``tuned=None`` (the default everywhere) leaves args, kwargs, and the
@@ -103,14 +72,14 @@ def _route(name: str, jit_body, oracle_body, *args, **kw):
         if tuned.get("use_oracle"):
             kw.pop("block_p", None)
             kw.pop("interpret", None)
-            return _timed(name, oracle_body, *args, **kw)
+            return oracle_body(*args, **kw)
         bp = tuned.get("block_p")
         if bp:
             kw.setdefault("block_p", int(bp))
     slots = slot_sharding_of(args[1]) if len(args) > 1 else None
     if slots is not None:
         kw["slot_sharding"] = slots
-    return _timed(name, jit_body, *args, **kw)
+    return jit_body(*args, **kw)
 
 
 def _pad_to(x, m, axis=-1):
@@ -250,8 +219,8 @@ def _seafl_aggregate_flat_oracle(global_flat, stacked_params, stacked_deltas,
 
 def seafl_aggregate_flat(*args, **kw):
     """Fused flat-buffer SEAFL aggregation, explicit deltas (see the jitted
-    body) — timed when kernel timing is installed, routed when ``tuned=``."""
-    return _route("seafl_aggregate_flat", _seafl_aggregate_flat_jit,
+    body), routed when ``tuned=``."""
+    return _route(_seafl_aggregate_flat_jit,
                   _seafl_aggregate_flat_oracle, *args, **kw)
 
 
@@ -301,10 +270,8 @@ def _seafl_aggregate_flat_from_params_oracle(global_flat, stacked_params,
 
 def seafl_aggregate_flat_from_params(*args, **kw):
     """Delta-free fused SEAFL aggregation: the server hot path (see the
-    jitted body) — timed when kernel timing is installed, routed when
-    ``tuned=``."""
-    return _route("seafl_aggregate_flat_from_params",
-                  _seafl_aggregate_flat_from_params_jit,
+    jitted body), routed when ``tuned=``."""
+    return _route(_seafl_aggregate_flat_from_params_jit,
                   _seafl_aggregate_flat_from_params_oracle, *args, **kw)
 
 
@@ -336,7 +303,7 @@ def _fedavg_aggregate_flat_oracle(global_flat, stacked_params, data_sizes):
 
 
 def fedavg_aggregate_flat(*args, **kw):
-    return _route("fedavg_aggregate_flat", _fedavg_aggregate_flat_jit,
+    return _route(_fedavg_aggregate_flat_jit,
                   _fedavg_aggregate_flat_oracle, *args, **kw)
 
 
@@ -364,7 +331,7 @@ def _fedbuff_aggregate_flat_oracle(global_flat, stacked_params, eta_g):
 
 
 def fedbuff_aggregate_flat(*args, **kw):
-    return _route("fedbuff_aggregate_flat", _fedbuff_aggregate_flat_jit,
+    return _route(_fedbuff_aggregate_flat_jit,
                   _fedbuff_aggregate_flat_oracle, *args, **kw)
 
 
@@ -391,5 +358,5 @@ def _fedasync_aggregate_flat_oracle(global_flat, client_flat, staleness,
 
 
 def fedasync_aggregate_flat(*args, **kw):
-    return _route("fedasync_aggregate_flat", _fedasync_aggregate_flat_jit,
+    return _route(_fedasync_aggregate_flat_jit,
                   _fedasync_aggregate_flat_oracle, *args, **kw)

@@ -17,6 +17,7 @@ Usage:
 from __future__ import annotations
 
 import argparse
+import contextlib
 import json
 import os
 import time
@@ -54,7 +55,7 @@ def build_lm_fl(arch: str, *, smoke: bool = True, n_clients: int = 8,
                 drift_band_edges=(0.8, 1.6),
                 drift_band_ratios=(0.025, 0.05, 0.1),
                 cohorts: str = "off", resync_batching: bool = False,
-                telemetry: bool = False, telemetry_kernels: bool = False,
+                telemetry: bool = False,
                 monitor: str = "off", slo=None, monitor_byte_budget=None,
                 scheduler: str = "random", autotune: str = "off"):
     cfg = model_config(arch, smoke)
@@ -106,7 +107,7 @@ def build_lm_fl(arch: str, *, smoke: bool = True, n_clients: int = 8,
                   drift_band_ratios=tuple(drift_band_ratios),
                   ingest_batch_chunks=ingest_batch,
                   cohorts=cohorts, resync_batching=resync_batching,
-                  telemetry=telemetry, telemetry_kernels=telemetry_kernels,
+                  telemetry=telemetry,
                   monitor=monitor, slo=slo,
                   monitor_byte_budget=monitor_byte_budget,
                   scheduler=scheduler, autotune=autotune)
@@ -334,11 +335,10 @@ def parse_args(argv=None):
                     help="enable the unified telemetry layer "
                          "(runtime/telemetry.py): counters, staleness/"
                          "weight histograms, wall + sim-clock spans")
-    ap.add_argument("--telemetry-kernels", action="store_true",
-                    default=False,
-                    help="also time each aggregation kernel call with "
-                         "block_until_ready (measurement-grade runs only: "
-                         "it serializes the XLA stream)")
+    ap.add_argument("--profile", default=None, metavar="DIR",
+                    help="capture a jax.profiler trace of the run into DIR: "
+                         "the seafl.* spans beside the device's programs "
+                         "(open in Perfetto or TensorBoard)")
     ap.add_argument("--log-jsonl", default=None, metavar="PATH",
                     help="append one structured JSON record per round plus "
                          "a final summary record to PATH")
@@ -417,7 +417,6 @@ def main(argv=None):
         ingest_batch=args.ingest_batch,
         cohorts=args.cohorts, resync_batching=args.resync_batching,
         telemetry=args.telemetry,
-        telemetry_kernels=args.telemetry_kernels,
         monitor=args.monitor, slo=args.slo,
         monitor_byte_budget=args.byte_budget,
         scheduler=args.scheduler, autotune=args.autotune)
@@ -440,26 +439,32 @@ def main(argv=None):
     last_logged = server.round
     jlog = JsonlLog(args.log_jsonl)
 
+    profile = (jax.profiler.trace(args.profile, create_perfetto_trace=True)
+               if args.profile else contextlib.nullcontext())
     # run in chunks so we can checkpoint between rounds
-    while server.round < args.rounds:
-        sim.run(max_rounds=min(server.round + args.ckpt_every, args.rounds))
-        wall = time.time() - t0
-        for h in sim.history:
-            if h["round"] > last_logged:
-                jlog.write(round_record(h, wall))
-        if sim.history:
-            rec = round_record(sim.history[-1], wall)
-            if sim.history[-1]["round"] > last_logged:
-                last_logged = sim.history[-1]["round"]
-            print(format_round(rec), flush=True)
-        if ck is not None and server.round > last_ck:
-            ck.save(server.round, server.checkpoint_trees(),
-                    extra=server.state_dict())
-            last_ck = server.round
-        if server.monitor is not None and server.monitor.slo_breached:
-            break
-        if not sim._heap:
-            break
+    with profile:
+        while server.round < args.rounds:
+            sim.run(max_rounds=min(server.round + args.ckpt_every,
+                                   args.rounds))
+            wall = time.time() - t0
+            for h in sim.history:
+                if h["round"] > last_logged:
+                    jlog.write(round_record(h, wall))
+            if sim.history:
+                rec = round_record(sim.history[-1], wall)
+                if sim.history[-1]["round"] > last_logged:
+                    last_logged = sim.history[-1]["round"]
+                print(format_round(rec), flush=True)
+            if ck is not None and server.round > last_ck:
+                ck.save(server.round, server.checkpoint_trees(),
+                        extra=server.state_dict())
+                last_ck = server.round
+            if server.monitor is not None and server.monitor.slo_breached:
+                break
+            if not sim._heap:
+                break
+    if args.profile:
+        print(f"[train] wrote profiler trace to {args.profile}")
     if ck is not None:
         ck.wait()   # the last async save must land before the process exits
     summary = summary_record(server, sim)

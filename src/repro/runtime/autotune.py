@@ -13,11 +13,10 @@ running.  This module makes the compute layer measurement-driven:
     interpret mode (CPU containers) — no more hand-flipped constant;
 
   * per-entry-point sweeps time every ``block_p`` candidate *and* the
-    XLA-oracle twin (``kernels/seafl_agg/ref.py``) with the same
-    block-until-ready clock the ``set_kernel_timing`` histograms use, so a
-    backend where the compiled kernel loses is routed to the oracle per
-    entry point, never process-wide (on a TPU, a kernel that fails to lower
-    at the default ``block_p`` raises instead);
+    XLA-oracle twin (``kernels/seafl_agg/ref.py``) with a block-until-ready
+    clock, so a backend where the compiled kernel loses is routed to the
+    oracle per entry point, never process-wide (on a TPU, a kernel that
+    fails to lower at the default ``block_p`` raises instead);
 
   * each measurement is cross-checked against the analytical roofline
     (the running device's entry in ``launch/mesh.DEVICE_PEAKS`` +
@@ -268,10 +267,8 @@ def load_table(prefer_user: bool = True,
 def _wall_timer(fn: Callable[[], object], label=None, reps: int = 3,
                 telemetry=None) -> float:
     """The sweep clock: block-until-ready wall seconds, best-of-``reps``
-    after a warm call — the same discipline as ``set_kernel_timing``'s
-    ``kernel.<name>_us`` histograms, and when a Telemetry is supplied the
-    measurement lands in those same histograms so the tuner and the
-    Perfetto trace read one clock."""
+    after a warm call; when a Telemetry is supplied the measurement also
+    lands in an ``autotune.<entry>_us`` histogram."""
     jax.block_until_ready(fn())                         # warm (trace + jit)
     best = float("inf")
     for _ in range(max(1, reps)):
@@ -280,7 +277,7 @@ def _wall_timer(fn: Callable[[], object], label=None, reps: int = 3,
         best = min(best, time.perf_counter() - t0)
     if telemetry is not None and getattr(telemetry, "enabled", False) \
             and label:
-        telemetry.histogram(f"kernel.{label[0]}_us", best * 1e6)
+        telemetry.histogram(f"autotune.{label[0]}_us", best * 1e6)
     return best
 
 

@@ -52,7 +52,6 @@ __all__ = [
     "WireFormat",
     "parse_spec",
     "make_wire_format",
-    "set_codec_timing",
     "encode_chunk",
     "decode_chunk",
     "decode_concat",
@@ -126,6 +125,11 @@ def _enc_int8_batch(x):
     scale = jnp.maximum(jnp.max(jnp.abs(xf), axis=1), 1e-12) / 127.0
     q = jnp.clip(jnp.round(xf / scale[:, None]), -127, 127).astype(jnp.int8)
     return {"q": q, "scale": scale}
+
+
+@jax.jit
+def _dec_bf16(x):
+    return x.astype(jnp.float32)
 
 
 @partial(jax.jit, static_argnames=("n",))
@@ -216,7 +220,7 @@ class _Bf16Codec(ChunkCodec):
         return _enc_bf16(x)
 
     def decode(self, payload, length, fmt):
-        return payload.astype(jnp.float32)
+        return _dec_bf16(payload)
 
     def encode_batch(self, x, fmt):
         return _enc_bf16(x)                       # elementwise: rank-free
@@ -373,46 +377,25 @@ def make_wire_format(spec: Optional[str],
 
 # --------------------------------------------------------- chunk plumbing
 
-# Opt-in codec wall timing (FLConfig.telemetry_kernels): the same
-# block-until-ready ``kernel.<name>_us`` histogram discipline as the
-# aggregate entry points in kernels/seafl_agg/ops.py, so the autotuner and
-# the Perfetto trace see encode/decode on the same clock.  None / disabled
-# (the default) leaves encode/decode un-synchronised and untouched.
-_KERNEL_TEL = None
-
-
-def set_codec_timing(telemetry: Optional[object]) -> None:
-    """Install (or clear, with None) the Telemetry that times
-    encode_chunk/decode_chunk.  Process-wide by design, like
-    ``set_kernel_timing``: a measurement mode, not protocol state."""
-    global _KERNEL_TEL
-    _KERNEL_TEL = telemetry
-
-
-def _timed(name: str, fn, *args):
-    tel = _KERNEL_TEL
-    if tel is None or not getattr(tel, "enabled", False):
-        return fn(*args)
-    import time
-    t0 = time.perf_counter()
-    out = jax.block_until_ready(fn(*args))
-    tel.histogram(f"kernel.{name}_us", (time.perf_counter() - t0) * 1e6)
-    return out
-
-
 def encode_chunk(x: jnp.ndarray, seq: int, start: int,
                  fmt: WireFormat) -> Chunk:
     """Encode one (n,) f32 window of the flat vector."""
     n = int(x.shape[0])
-    payload = _timed(f"encode_{fmt.scheme}", fmt.codec.encode, x, fmt)
     return Chunk(seq=seq, start=start, length=n,
-                 payload=payload, nbytes=fmt.chunk_wire_bytes(n))
+                 payload=fmt.codec.encode(x, fmt),
+                 nbytes=fmt.chunk_wire_bytes(n))
 
 
 def decode_chunk(chunk: Chunk, fmt: WireFormat) -> jnp.ndarray:
     """Decode one chunk back to its (length,) f32 window."""
-    return _timed(f"decode_{fmt.scheme}", fmt.codec.decode,
-                  chunk.payload, chunk.length, fmt)
+    return fmt.codec.decode(chunk.payload, chunk.length, fmt)
+
+
+@jax.jit
+def _join_chunks(*vals):
+    """One join of at most 16 decoded windows: the ``concatenate`` program
+    eager ``jnp.concatenate`` runs, under a name of its own for profiles."""
+    return jax.lax.concatenate(vals, 0)
 
 
 def decode_concat(chunks: list[Chunk], fmt: WireFormat) -> jnp.ndarray:
@@ -420,7 +403,12 @@ def decode_concat(chunks: list[Chunk], fmt: WireFormat) -> jnp.ndarray:
     vals = [decode_chunk(c, fmt) for c in chunks if c.length]
     if not vals:
         return jnp.zeros((0,), jnp.float32)
-    return jnp.concatenate(vals) if len(vals) > 1 else vals[0]
+    # jnp.concatenate's tree of at most 16-way joins, in its order; jit's
+    # dispatch of each join is far cheaper on the host than eager's.
+    while len(vals) > 1:
+        groups = [vals[i:i + 16] for i in range(0, len(vals), 16)]
+        vals = [_join_chunks(*g) if len(g) > 1 else g[0] for g in groups]
+    return vals[0]
 
 
 def encode_flat(vec: jnp.ndarray, fmt: WireFormat) -> list[Chunk]:

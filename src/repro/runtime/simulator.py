@@ -537,7 +537,8 @@ class FLSimulation:
             wait, _ = self.server.scheduler.max_wait(elig_idle)
             rec["sched_max_wait"] = round(wait, 1)
         if self.eval_fn is not None and (agg.round % self.eval_every == 0):
-            rec["acc"] = float(self.eval_fn(self.server.params))
+            with self.tel.span("eval"):
+                rec["acc"] = float(self.eval_fn(self.server.params))
         if self.tel.enabled:
             # rolling metrics snapshot rides with the round record (compact:
             # histogram summaries only) — history keys are unchanged when
@@ -658,72 +659,75 @@ class FLSimulation:
             if not ev.valid:
                 continue
             self.now = ev.time
-            if ev.kind == "upload":
-                self._handle_upload(ev.data["cid"])
-            elif ev.kind == "arrive":
-                fl = self._inflight.get(ev.data["cid"])
-                if fl is not None and fl.payload is not None:
-                    self.server.deliver_dispatch(fl.cid, fl.payload)
-                    self.tel.sim_span(
-                        "dispatch", fl.sched, self.now,
-                        track=f"client{fl.cid}", bytes=fl.payload.nbytes,
-                        version=fl.payload.target_version,
-                        scheme=fl.payload.scheme)
-            elif ev.kind == "deliver":
-                self._handle_deliver(ev.data["cid"], ev.data["payload"],
-                                     ev.data["loss"],
-                                     ev.data.get("up_t0"),
-                                     ev.data.get("sched_t0"))
-            elif ev.kind == "notify":
-                self._handle_notify(ev.data["cid"])
-            elif ev.kind == "fail":
-                cid = ev.data["cid"]
-                if self._kill_inflight(cid, instant="crash"):
-                    self._crashed.add(cid)
-                    self._push(self.now + self.cfg.recover_after,
-                               "recover", cid=cid)
-            elif ev.kind == "recover":
-                self._crashed.discard(ev.data["cid"])
-                self.server.recover(ev.data["cid"])
-            elif ev.kind == "avail_off":
-                cid = ev.data["cid"]
-                self._offline.add(cid)
-                self.tel.sim_instant("offline", self.now,
-                                     track=f"client{cid}")
-                # going offline mid-round kills the in-flight
-                # transfer/training exactly like a crash: tracking drops,
-                # the return dispatch ships a full snapshot
-                self._kill_inflight(cid)
-                self._push(self.now + self.avail.next_delay(cid, False),
-                           "avail_on", cid=cid)
-            elif ev.kind == "avail_on":
-                cid = ev.data["cid"]
-                self._offline.discard(cid)
-                self.tel.sim_instant("online", self.now,
-                                     track=f"client{cid}")
-                self._push(self.now + self.avail.next_delay(cid, True),
-                           "avail_off", cid=cid)
-                if cid in self._deferred:
-                    self._deferred.discard(cid)
-                    if (len(self.server.active)
-                            < self.server.cfg.concurrency):
-                        # the parked dispatch goes out now, re-marked
-                        # against the current global (tracking stayed
-                        # honest: the old decision's version was never
-                        # delivered)
-                        self.server.mark_dispatched(cid)
-                        self.server.scheduler.note_dispatched(cid)
-                        self._dispatch(cid)
-                    else:
-                        # its slot was refilled while it was away: the
-                        # promise lapses, the client rejoins the pool
+            # one span per event, named by its kind: the simulator's host
+            # time per event kind in a profile
+            with self.tel.span(f"sim.{ev.kind}"):
+                if ev.kind == "upload":
+                    self._handle_upload(ev.data["cid"])
+                elif ev.kind == "arrive":
+                    fl = self._inflight.get(ev.data["cid"])
+                    if fl is not None and fl.payload is not None:
+                        self.server.deliver_dispatch(fl.cid, fl.payload)
+                        self.tel.sim_span(
+                            "dispatch", fl.sched, self.now,
+                            track=f"client{fl.cid}", bytes=fl.payload.nbytes,
+                            version=fl.payload.target_version,
+                            scheme=fl.payload.scheme)
+                elif ev.kind == "deliver":
+                    self._handle_deliver(ev.data["cid"], ev.data["payload"],
+                                         ev.data["loss"],
+                                         ev.data.get("up_t0"),
+                                         ev.data.get("sched_t0"))
+                elif ev.kind == "notify":
+                    self._handle_notify(ev.data["cid"])
+                elif ev.kind == "fail":
+                    cid = ev.data["cid"]
+                    if self._kill_inflight(cid, instant="crash"):
+                        self._crashed.add(cid)
+                        self._push(self.now + self.cfg.recover_after,
+                                   "recover", cid=cid)
+                elif ev.kind == "recover":
+                    self._crashed.discard(ev.data["cid"])
+                    self.server.recover(ev.data["cid"])
+                elif ev.kind == "avail_off":
+                    cid = ev.data["cid"]
+                    self._offline.add(cid)
+                    self.tel.sim_instant("offline", self.now,
+                                         track=f"client{cid}")
+                    # going offline mid-round kills the in-flight
+                    # transfer/training exactly like a crash: tracking drops,
+                    # the return dispatch ships a full snapshot
+                    self._kill_inflight(cid)
+                    self._push(self.now + self.avail.next_delay(cid, False),
+                               "avail_on", cid=cid)
+                elif ev.kind == "avail_on":
+                    cid = ev.data["cid"]
+                    self._offline.discard(cid)
+                    self.tel.sim_instant("online", self.now,
+                                         track=f"client{cid}")
+                    self._push(self.now + self.avail.next_delay(cid, True),
+                               "avail_off", cid=cid)
+                    if cid in self._deferred:
+                        self._deferred.discard(cid)
+                        if (len(self.server.active)
+                                < self.server.cfg.concurrency):
+                            # the parked dispatch goes out now, re-marked
+                            # against the current global (tracking stayed
+                            # honest: the old decision's version was never
+                            # delivered)
+                            self.server.mark_dispatched(cid)
+                            self.server.scheduler.note_dispatched(cid)
+                            self._dispatch(cid)
+                        else:
+                            # its slot was refilled while it was away: the
+                            # promise lapses, the client rejoins the pool
+                            self.server.recover(cid)
+                    elif cid not in self._crashed:
+                        # back in the pool (crash recovery, if pending, keeps
+                        # its own clock); spare concurrency refills from the
+                        # now-larger eligible pool
                         self.server.recover(cid)
-                elif cid not in self._crashed:
-                    # back in the pool (crash recovery, if pending, keeps
-                    # its own clock); spare concurrency refills from the
-                    # now-larger eligible pool
-                    self.server.recover(cid)
-                    self._top_up()
+                        self._top_up()
             if target_acc is not None and self.history:
                 accs = [h.get("acc", 0.0) for h in self.history]
                 if accs and max(accs) >= target_acc:

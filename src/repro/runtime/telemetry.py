@@ -1,16 +1,20 @@
-"""Process-wide zero-dep telemetry: counters/gauges/histograms + span tracing.
+"""Process-wide telemetry: counters/gauges/histograms + span tracing.
 
 One `Telemetry` registry is threaded through every layer of the FL stack
-(server, dispatch, ingest, cohorts, policy, kernels, simulator).  It is
-**off by default** and, when disabled, every record call is a no-op that
-touches no RNG, allocates nothing observable, and changes no bytes — the
-same zero-behavioral-change discipline as ``cohorts='off'`` (pinned by
-`tests/test_telemetry.py`).
+(server, dispatch, ingest, cohorts, policy, simulator).  It is **off by
+default** and, when disabled, every record call is a no-op that touches no
+RNG, allocates nothing observable, and changes no bytes (a span is then
+only its profiler annotation) — the same zero-behavioral-change discipline
+as ``cohorts='off'`` (pinned by `tests/test_telemetry.py`).
 
 Two clocks coexist:
 
-* **wall clock** — `span(...)` measures real `perf_counter` time around
-  server-side compute (aggregation, encode, kernel launches).
+* **wall clock** — `span(name)` is always a ``jax.profiler.TraceAnnotation``
+  named ``seafl.<name>``, so the span lands in a profiler trace on the
+  device's clock (a TraceMe activity check, well under a microsecond, when
+  no profile is being captured); with the registry enabled it also
+  records the real `perf_counter` time as a ``<name>_ms`` histogram and a
+  Chrome-trace record.
 * **simulated clock** — `sim_span(...)` / `sim_instant(...)` take explicit
   `t0`/`t1` from `FLSimulation`'s event heap, one track per client.
 
@@ -29,6 +33,8 @@ from __future__ import annotations
 import json
 import time
 from typing import Any, Dict, Iterator, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 # pid layout of the exported trace: Perfetto renders one "process" per
 # clock domain so simulated seconds never share an axis with wall seconds.
@@ -49,36 +55,28 @@ def _key(name: str, labels: Dict[str, Any]) -> str:
     return f"{name}[{inner}]"
 
 
-class _NullSpan:
-    """Reusable no-op context manager for disabled telemetry."""
-
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-
-_NULL_SPAN = _NullSpan()
+# Prefix of every span's name in the profiler trace.
+TRACE_PREFIX = "seafl."
 
 
 class _WallSpan:
-    __slots__ = ("_tel", "name", "attrs", "_t0")
+    __slots__ = ("_tel", "name", "attrs", "_t0", "_ann")
 
     def __init__(self, tel: "Telemetry", name: str, attrs: Dict[str, Any]):
         self._tel = tel
         self.name = name
         self.attrs = attrs
+        self._ann = TraceAnnotation(TRACE_PREFIX + name)
 
     def __enter__(self):
+        self._ann.__enter__()
         self._t0 = time.perf_counter()
         self._tel._wall_stack.append(self.name)
         return self
 
     def __exit__(self, *exc):
         t1 = time.perf_counter()
+        self._ann.__exit__(*exc)
         tel = self._tel
         tel._wall_stack.pop()
         tel._push_span({
@@ -145,9 +143,12 @@ class Telemetry:
 
     # --------------------------------------------------------------- spans
     def span(self, name: str, **attrs):
-        """Wall-clock span around server-side compute (context manager)."""
+        """Wall-clock span (context manager): a profiler annotation named
+        ``seafl.<name>`` always, and with the registry enabled also a
+        ``<name>_ms`` histogram sample and a Chrome-trace record.  Open
+        one per call, upload or round, never per chunk, leaf or batch."""
         if not self.enabled:
-            return _NULL_SPAN
+            return TraceAnnotation(TRACE_PREFIX + name)
         return _WallSpan(self, name, attrs)
 
     def _sim_tid(self, track: str) -> int:

@@ -27,6 +27,7 @@ client-side encoder with its EF fold, and the server-side streaming ingest
 from __future__ import annotations
 
 from dataclasses import dataclass, field
+from functools import partial
 from typing import Optional
 
 import jax
@@ -255,6 +256,22 @@ class IngestBatcher:
         self.tel.histogram("ingest.flush_chunks", len(batch))
 
 
+# The delta base add's eager ops as programs of their own name, so a
+# profile tells the ingest's device work from every other eager op: a
+# rename, the same HLO.  (The join is ``codecs._join_chunks``.)
+
+@partial(jax.jit, static_argnums=(1, 2))
+def _ingest_base(base, start: int, end: int):
+    """The delta base's ``[start, end)`` window (eager ``lax.slice``)."""
+    return jax.lax.slice(base, (start,), (end,))
+
+
+@jax.jit
+def _ingest_add(vals, base):
+    """Decoded delta plus its base window (eager ``+``)."""
+    return vals + base
+
+
 class IngestSession:
     """Server-side decoder for one in-flight upload.
 
@@ -270,7 +287,8 @@ class IngestSession:
     def __init__(self, buffer, slot: int, fmt: WireFormat,
                  base_flat: Optional[jnp.ndarray] = None,
                  param_size: Optional[int] = None,
-                 batcher: Optional[IngestBatcher] = None):
+                 batcher: Optional[IngestBatcher] = None,
+                 telemetry: Optional[Telemetry] = None):
         if fmt.delta_coded and base_flat is None:
             raise ValueError(f"wire scheme {fmt.scheme} is delta-coded and "
                              "needs the dispatch-version base to decode")
@@ -281,6 +299,7 @@ class IngestSession:
         self.param_size = int(param_size if param_size is not None
                               else buffer.param_size)
         self.batcher = batcher
+        self.tel = _tel_of(telemetry)
         self.covered = 0             # elements ingested so far (in order)
         self.nbytes = 0              # wire bytes seen
 
@@ -296,8 +315,8 @@ class IngestSession:
         self._check(chunk, self.covered)
         vals = decode_chunk(chunk, self.fmt)
         if self.fmt.delta_coded:
-            vals = vals + jax.lax.slice(
-                self.base, (chunk.start,), (chunk.start + chunk.length,))
+            vals = _ingest_add(vals, _ingest_base(
+                self.base, chunk.start, chunk.start + chunk.length))
         if chunk.length:
             if self.batcher is not None:
                 self.batcher.enqueue(self.slot, chunk.start, vals)
@@ -328,10 +347,13 @@ class IngestSession:
             end += chunk.length
             nbytes += chunk.nbytes
         if end > start:
-            vals = decode_concat(chunks, self.fmt)
-            if self.fmt.delta_coded:
-                vals = vals + jax.lax.slice(self.base, (start,), (end,))
-            self.buffer.write_range(self.slot, start, vals)
+            with self.tel.span("ingest.decode"):
+                vals = decode_concat(chunks, self.fmt)
+                if self.fmt.delta_coded:
+                    vals = _ingest_add(vals,
+                                       _ingest_base(self.base, start, end))
+            with self.tel.span("ingest.write"):
+                self.buffer.write_range(self.slot, start, vals)
         self.covered = end
         self.nbytes += nbytes
 

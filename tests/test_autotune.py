@@ -339,24 +339,3 @@ def test_batcher_cache_miss_falls_back_to_probe(monkeypatch):
     b.enqueue(0, 0, jnp.ones((1 << 12,), jnp.float32))
     assert probed, "tuned miss (None) must fall back to the probe"
     assert b._bypass is False and b.pending == 1
-
-
-def test_codec_timing_histograms():
-    """telemetry_kernels extends to codecs: encode/decode record
-    kernel.<op>_<scheme>_us histograms through set_codec_timing."""
-    from repro.runtime import codecs
-    from repro.runtime.telemetry import Telemetry
-
-    tel = Telemetry(enabled=True)
-    codecs.set_codec_timing(tel)
-    try:
-        fmt = codecs.make_wire_format("topk:0.1", chunk_elems=1024)
-        vec = jnp.arange(2048, dtype=jnp.float32)
-        chunks = codecs.encode_flat(vec, fmt)
-        codecs.decode_concat(chunks, fmt)
-    finally:
-        codecs.set_codec_timing(None)
-    hists = tel.snapshot()["histograms"]
-    assert "kernel.encode_topk_us" in hists
-    assert "kernel.decode_topk_us" in hists
-    assert hists["kernel.encode_topk_us"]["count"] >= 1

@@ -342,26 +342,6 @@ def test_policy_band_telemetry():
     assert snap["histograms"]["policy.drift_x_hist"]["count"] == 2
 
 
-def test_kernel_timing_opt_in():
-    from repro.kernels.seafl_agg import ops
-    tel = Telemetry(enabled=True)
-    ops.set_kernel_timing(tel)
-    try:
-        import jax.numpy as jnp
-        g = jnp.zeros(16, jnp.float32)
-        upd = jnp.ones((2, 16), jnp.float32)
-        st = jnp.zeros(2, jnp.float32)
-        ns = jnp.ones(2, jnp.float32)
-        ops.seafl_aggregate_flat_from_params(g, upd, st, ns,
-                                            0.25, 0.5, 10.0, 1.0)
-        snap = tel.snapshot()
-        ks = [k for k in snap["histograms"] if k.startswith("kernel.")]
-        assert ks, snap["histograms"].keys()
-        assert all(v >= 0 for v in snap["histograms"][ks[0]]["values"])
-    finally:
-        ops.set_kernel_timing(None)
-
-
 # ------------------------------------------------------- train.py records
 
 def test_round_record_and_formatter_agree():
