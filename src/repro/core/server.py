@@ -674,10 +674,12 @@ class SeaflServer:
     def ingest_payload(self, payload: UploadPayload,
                        recv_time: float = 0.0) -> Optional[AggregationEvent]:
         """Atomic ingest of a whole wire payload (the simulator's deliver
-        event and the legacy ``on_update`` both land here).  The drained
-        chunks are adjacent windows of one slot, so they coalesce into a
-        single donated dynamic-update (``IngestSession.write_all``) instead
-        of one dispatch per chunk."""
+        event, which ``launch/train.py`` drives too, and the legacy
+        ``on_update`` land here).  The drained chunks are adjacent windows
+        of one slot: each is decoded on its own, and
+        ``IngestSession.write_all`` lands them all with one donated device
+        program that joins them, adds the delta base on a delta-coded wire,
+        and writes the slot in place."""
         with self.tel.span("ingest"):
             sess = self.begin_ingest(payload.cid, payload.version,
                                      payload.n_epochs, recv_time=recv_time)

@@ -256,9 +256,10 @@ class IngestBatcher:
         self.tel.histogram("ingest.flush_chunks", len(batch))
 
 
-# The delta base add's eager ops as programs of their own name, so a
-# profile tells the ingest's device work from every other eager op: a
-# rename, the same HLO.  (The join is ``codecs._join_chunks``.)
+# ``IngestSession.write``'s per-chunk delta base add as programs of their
+# own name, so a profile tells the ingest's device work from every other
+# eager op: a rename, the same HLO.  (``write_all`` adds the base inside the
+# buffer's one write program.)
 
 @partial(jax.jit, static_argnums=(1, 2))
 def _ingest_base(base, start: int, end: int):
@@ -329,12 +330,14 @@ class IngestSession:
         """Coalesced write of one drained batch of in-order chunks.
 
         The sequential wire framing makes a drained batch one contiguous
-        window, so instead of one donated ``dynamic_update_slice`` dispatch
-        per chunk (the per-chunk overhead flagged in BENCH_ingest), the
-        decoded chunks are concatenated — and the delta base added — once,
-        and the whole run lands in the slot with a *single* donated write.
+        window, so each chunk is decoded on its own (the identity for raw
+        f32 windows) and the decoded windows go to the buffer as separate
+        arguments of *one* donated device program
+        (``UpdateBuffer.write_range``): it joins them, adds the base's
+        window where the wire is delta-coded, and writes the run into the
+        slot in place.  No join or base add is dispatched on its own.
         Values are bit-identical to chunk-by-chunk ``write`` (same decode,
-        same elementwise base add, same destination elements).
+        exact join, same elementwise base add, same destination elements).
 
         The whole batch is validated before any state changes: a bad batch
         raises with the session untouched, so the driver's redelivery path
@@ -347,13 +350,16 @@ class IngestSession:
             end += chunk.length
             nbytes += chunk.nbytes
         if end > start:
+            from repro.core.buffer import DeltaWindows   # import cycle
             with self.tel.span("ingest.decode"):
-                vals = decode_concat(chunks, self.fmt)
-                if self.fmt.delta_coded:
-                    vals = _ingest_add(vals,
-                                       _ingest_base(self.base, start, end))
+                vals = [decode_chunk(c, self.fmt) for c in chunks if c.length]
+            n_windows = len(vals)
+            if self.fmt.delta_coded:
+                vals = DeltaWindows(vals, self.base)
             with self.tel.span("ingest.write"):
                 self.buffer.write_range(self.slot, start, vals)
+            self.tel.counter("ingest.fused_writes")
+            self.tel.counter("ingest.fused_chunks", n_windows)
         self.covered = end
         self.nbytes += nbytes
 

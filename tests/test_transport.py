@@ -718,3 +718,130 @@ print("SHARDED_OK")
     out = subprocess.run([sys.executable, "-c", code], env=env,
                          capture_output=True, text=True, timeout=240)
     assert "SHARDED_OK" in out.stdout, out.stderr
+
+
+# ------------------------------------------------- one write program per upload
+
+def _bench_ingest_modules():
+    """``MODULES`` of the benchmark's ``ingest_device_ms.server`` metric."""
+    import runpy
+    from pathlib import Path
+    path = (Path(__file__).resolve().parents[1] / "benchmarks" / "chip"
+            / "metrics" / "ingest_device_ms.server.py")
+    return runpy.run_path(str(path))["MODULES"]
+
+
+@pytest.mark.parametrize("spec", [None, "int8"])
+def test_ingest_payload_is_one_write_program_and_no_join(spec, monkeypatch):
+    """Each ``ingest_payload`` dispatches the buffer's write program once
+    and no chunk join, on a raw and on a delta-coded wire."""
+    from repro.core import buffer as buffer_mod
+    from repro.runtime import codecs
+
+    s = make_server(K=4, compression=spec, chunk_elems=13)   # 7 windows
+    s.start()
+    rng = np.random.default_rng(5)
+    joins, writes = [0], [0]
+    join, write = codecs._join_chunks, buffer_mod._write_range
+
+    def counted_join(*a):
+        joins[0] += 1
+        return join(*a)
+
+    def counted_write(*a):
+        writes[0] += 1
+        return write(*a)
+
+    monkeypatch.setattr(codecs, "_join_chunks", counted_join)
+    monkeypatch.setattr(buffer_mod, "_write_range", counted_write)
+    for i in range(s.cfg.buffer_size - 1):          # stop short of the trigger
+        cid = sorted(s.active)[0]
+        payload = s.encode_update(
+            cid, perturbed(s.params_at(s.active[cid]), rng), 5)
+        joins[0] = writes[0] = 0
+        assert s.ingest_payload(payload) is None
+        assert (joins[0], writes[0]) == (0, 1)
+
+
+def test_write_program_compiles_once_per_model():
+    """Five uploads of one model: one entry in the write program's jit
+    cache (the window count and the tail length are fixed by P and the
+    chunk size; the slot and start are traced scalars)."""
+    from repro.core import buffer as buffer_mod
+
+    s = make_server(n=12, M=6, K=6, chunk_elems=11)   # P = 90: 9 windows
+    s.start()
+    rng = np.random.default_rng(6)
+    buffer_mod._write_range.clear_cache()
+    for _ in range(5):
+        cid = sorted(s.active)[0]
+        assert s.on_update(cid, perturbed(s.params_at(s.active[cid]), rng),
+                           n_epochs=5) is None
+    assert buffer_mod._write_range._cache_size() == 1
+
+
+@pytest.mark.parametrize("buffer_dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("spec", ["f32", "bf16", "topk:0.25", "int8"])
+def test_write_all_matches_chunk_by_chunk_write(spec, buffer_dtype):
+    """Slot contents after ``write_all`` are bit-identical to chunk-by-chunk
+    ``write``, with a short tail window, a P that is not a multiple of 128,
+    more windows than one in-program join takes, and a run split in two so
+    the second write starts mid-row."""
+    rng = np.random.default_rng(12)
+    P, ce = 1000, 24                               # 41 full windows + 16
+    fmt = make_wire_format(spec, ce)
+    base = flat_vec(P, rng)
+    vec = base + 0.1 * flat_vec(P, rng)
+    chunks = encode_update(0, 0, 1, vec, fmt,
+                           base_flat=base if fmt.delta_coded else None).chunks
+    assert chunks[-1].length == 16 and P % 128
+    rows = []
+    for fused in (False, True):
+        buf = UpdateBuffer(2, P, dtype=buffer_dtype)
+        buf.reserve(Update(9, 1, 0, 1))            # the upload lands in row 1
+        slot = buf.reserve(Update(0, 1, 0, 1))
+        sess = IngestSession(buf, slot, fmt,
+                             base_flat=base if fmt.delta_coded else None)
+        if fused:
+            sess.write_all(chunks[:7])
+            sess.write_all(chunks[7:])
+        else:
+            for c in chunks:
+                sess.write(c)
+        assert sess.complete
+        rows.append(np.asarray(buf._buf[slot].astype(jnp.float32)))
+    np.testing.assert_array_equal(rows[0], rows[1])
+
+
+def test_write_program_is_read_by_the_ingest_device_metric():
+    """The lowered write program's module name is one that the benchmark's
+    ``ingest_device_ms.server`` reads."""
+    from repro.core.buffer import _write_range
+
+    buf = jnp.zeros((2, 40), jnp.float32)
+    parts = (jnp.ones((16,)), jnp.ones((16,)), jnp.ones((8,)))
+    for base in (None, jnp.zeros((40,))):
+        first = _write_range.lower(buf, np.int32(0), np.int32(0), parts,
+                                   base).as_text().splitlines()[0]
+        name = first.split()[1].lstrip("@")
+        assert name == "jit__write_range"
+        assert any(m in name for m in _bench_ingest_modules())
+
+
+@pytest.mark.parametrize("spec", [None, "topk:0.25"])
+def test_fused_write_counters_equal_commits_and_windows(spec):
+    """With telemetry on, ``ingest.fused_writes`` equals ``ingest.commits``
+    and ``ingest.fused_chunks`` the windows sent."""
+    s = make_server(K=3, compression=spec, chunk_elems=13, telemetry=True)
+    s.start()
+    rng = np.random.default_rng(7)
+    windows = 0
+    for _ in range(5):                             # one aggregation on the way
+        cid = sorted(s.active)[0]
+        payload = s.encode_update(
+            cid, perturbed(s.params_at(s.active[cid]), rng), 5)
+        windows += sum(1 for c in payload.chunks if c.length)
+        s.ingest_payload(payload)
+    c = s.tel.snapshot()["counters"]
+    assert c["ingest.fused_writes"] == c["ingest.commits"] == 5
+    assert c["ingest.fused_chunks"] == windows == 5 * 7
