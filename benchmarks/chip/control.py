@@ -5,11 +5,11 @@
         --seeds 1,2,3 --seconds 3 [--control 1] [--trace 0]
 
 For each seed, in one process: the cell's set-up and a short window at the
-cell's own load, then the numbers ``correct`` compares, read for the
-program against the plain reference and, with ``--control 1``, for the
-control (the reference in bfloat16 put in the program's place) against the
-same reference.  Prints one JSON line per seed.  The benchmark's own runs
-never run the control.
+cell's own load, placed over its chips as ``run.py`` places a run, then
+the numbers ``correct`` compares, read for the program against the plain
+reference and, with ``--control 1``, for the control (the reference in
+bfloat16 put in the program's place) against the same reference.  Prints
+one JSON line per seed.  The benchmark's own runs never run the control.
 """
 from __future__ import annotations
 
@@ -25,27 +25,30 @@ for _p in (str(ROOT / "src"), str(ROOT)):
         sys.path.insert(0, _p)
 
 from benchmarks.chip import device, spec  # noqa: E402
-from benchmarks.chip.run import driver_of  # noqa: E402
+from benchmarks.chip.run import driver_of, placement  # noqa: E402
 
 
 def readings(workload: str, seed: int, seconds: float, with_control: bool,
              *, bench=None, files=None, detail: bool = False,
              fault: str = "") -> dict:
+    import jax
+
     bench = spec.load() if bench is None else bench
     cell = spec.cell(bench, workload)
     files = files or {}
     config = files.get("config") or spec.config(bench, cell)
     traffic = files.get("traffic") or spec.traffic(cell)
     drv = driver_of(traffic)
-    st = drv.setup(config, traffic, seed)
-    drv.window(st, seconds)
-    drv.release(st)
-    gc.collect()
-    out = {"seed": seed, "program": drv.check(st, detail)}
-    if with_control:
-        out["control"] = drv.control(st, detail)
-    for f in filter(None, fault.split(",")):
-        out[f] = drv.control(st, detail, fault=f)
+    with placement(jax.devices()[:cell["chips"]]):
+        st = drv.setup(config, traffic, seed)
+        drv.window(st, seconds)
+        drv.release(st)
+        gc.collect()
+        out = {"seed": seed, "program": drv.check(st, detail)}
+        if with_control:
+            out["control"] = drv.control(st, detail)
+        for f in filter(None, fault.split(",")):
+            out[f] = drv.control(st, detail, fault=f)
     return out
 
 

@@ -32,10 +32,13 @@ def ingest_least_bytes(p: int, wire_bytes_per_elem: float,
     return p * wire_bytes_per_elem + p * buf_itemsize
 
 
-def least_seconds(nbytes: float, flops: float, pk: dict) -> float:
-    """Roofline time: the larger of bytes over HBM bandwidth and FLOPs over
-    the bf16 peak."""
-    return max(nbytes / pk["hbm_bw"], flops / pk["flops_bf16"])
+def least_seconds(nbytes: float, flops: float, pk: dict,
+                  chips: int = 1) -> float:
+    """Roofline time of work spread over ``chips`` chips of peaks ``pk``:
+    the larger of bytes over HBM bandwidth and FLOPs over the bf16 peak,
+    over the chips.  ``nbytes`` and ``flops`` count the whole work once,
+    whatever number of chips does it."""
+    return max(nbytes / pk["hbm_bw"], flops / pk["flops_bf16"]) / chips
 
 
 def conv_flops(h_out: int, w_out: int, kh: int, kw: int, cin: int,

@@ -18,6 +18,12 @@ Traffic parameters (``traffic/<name>.json``):
     sampled_globals window rounds whose new global is compared with the
                     reference (drawn from the seed), besides the last
 
+Each global kept for the check is copied to host memory when it is taken,
+so the window's device memory holds no copy made for the check
+(``State.keep``).  On several chips (``run.py`` installs a ``pod`` mesh),
+the seeded tree, the flat global and every pool row are made split over
+the mesh (``weights.sharding``).
+
 The window drives ``ingest_payload`` (which aggregates every K uploads)
 and, after each aggregation, ``encode_dispatch`` and ``deliver_dispatch``
 for every client the server sends back to training, as ``FLSimulation``
@@ -29,6 +35,7 @@ from __future__ import annotations
 import dataclasses
 import sys
 import time
+from concurrent.futures import ThreadPoolExecutor
 
 import numpy as np
 
@@ -78,6 +85,7 @@ class State:
         self.rounds: list[dict] = []
         self.uploads: list[tuple] = []
         self.kept_globals: dict[int, object] = {}
+        self.copier = ThreadPoolExecutor(max_workers=1)
         self.sampled: list[int] = []
         self.sample_rng = np.random.default_rng([seed, 1])
 
@@ -87,6 +95,17 @@ class State:
 
         k = jax.random.fold_in(weights.key(self.seed), 1_000_003 + i)
         return _step(g0, k, self.traffic["step_scale"])
+
+    def keep(self) -> None:
+        """Keep the server's current global for the check, in host memory:
+        the copy starts at once and a worker thread waits for it, so the
+        loop never does.  It lands in ``kept_globals`` as a numpy array at
+        ``release``; the device buffer goes once the copy is done and the
+        server has moved on."""
+        g = self.server.global_flat
+        g.copy_to_host_async()
+        self.kept_globals[len(self.rounds)] = self.copier.submit(np.asarray,
+                                                                 g)
 
     def next_uploader(self) -> int:
         inflight = sorted(self.held)
@@ -101,12 +120,19 @@ _STEP = {}
 
 
 def _step(g0, k, scale):
+    return step_program(weights.sharding(g0.shape, 0))(g0, k, scale)
+
+
+def step_program(where):
+    """The jitted ``(g0, key, scale) -> row`` of a pool row, its output in
+    ``where`` (None: where JAX puts it)."""
     import jax
 
-    if "fn" not in _STEP:
-        _STEP["fn"] = jax.jit(lambda g, key, s: g + s * jax.random.normal(
-            key, g.shape, g.dtype))
-    return _STEP["fn"](g0, k, scale)
+    if where not in _STEP:
+        _STEP[where] = weights.placed(
+            lambda g, key, s: g + s * jax.random.normal(key, g.shape,
+                                                        g.dtype), where)
+    return _STEP[where]
 
 
 def one_round(st: State, spans: dict) -> None:
@@ -160,7 +186,7 @@ def setup(config: dict, traffic: dict, seed: int) -> State:
         one_round(st, spans)
     print(f"[chipbench] setup phases: server and pool {t1 - t0:.3f} s, "
           f"warm-up rounds {time.perf_counter() - t1:.3f} s", file=sys.stderr)
-    st.kept_globals[len(st.rounds)] = st.server.global_flat
+    st.keep()
     return st
 
 
@@ -176,7 +202,7 @@ def sample(st: State, n: int) -> None:
             return
         st.kept_globals.pop(st.sampled[j])
         st.sampled[j] = now
-    st.kept_globals[now] = st.server.global_flat
+    st.keep()
 
 
 def window(st: State, seconds: float) -> dict:
@@ -191,7 +217,7 @@ def window(st: State, seconds: float) -> dict:
         elapsed = time.perf_counter() - t0
         if elapsed >= seconds:
             break
-    st.kept_globals[len(st.rounds)] = st.server.global_flat
+    st.keep()
     fl = st.fl
     buf_item = 2 if fl.buffer_dtype == "bfloat16" else 4
     wire = st.server.wire
@@ -212,63 +238,74 @@ def window(st: State, seconds: float) -> dict:
 
 
 def release(st: State) -> None:
-    """Free the program's state; the sampled globals and the records
-    stay for the check."""
+    """Free the program's state; the sampled globals, copied to host
+    memory, and the records stay for the check."""
     st.server = None
     st.pool = None
+    st.copier.shutdown(wait=True)
+    st.kept_globals = {r: c.result() for r, c in st.kept_globals.items()}
 
 
-def reference(st: State, dtype) -> dict:
+def reference(st: State, dtype, against: dict | None = None) -> dict:
     """Every round replayed by the plain reference from the seed's global
-    and pool: the weights of each round and the new global of each round
-    whose program global was kept."""
+    and pool, in P-blocks and split as the cell's arrays are.  Returns the
+    weights of each round and, for each round whose program global was
+    kept, either the widest gaps ``(d, a, b)`` of the reference's new global
+    to the kept copy in ``against`` (max |a - b|, max |against|, max |new|),
+    compared on the device block by block, or, with ``against`` None, a
+    host copy of the new global."""
     import jax
-    import jax.numpy as jnp
 
+    from repro.sharding import current_rules
+
+    mesh = current_rules().mesh
     g = st.g0_fn()
-    pool = jnp.stack([st.pool_row(g, i) for i in range(st.traffic["pool"])])
-    agg = jax.jit(lambda g_, rows, sz, tau: ref.aggregate(
-        g_, rows, sz, tau, st.hyper, dtype))
+    pool = tuple(st.pool_row(g, i) for i in range(st.traffic["pool"]))
+    step = ref.replay_round(st.hyper, dtype, mesh)
+    widest = ref.widest_gap(mesh)
     out = {"weights": [], "globals": {}}
     for r, rec in enumerate(st.rounds):
         idx = np.asarray([u[0] for u in rec["uploads"]], np.int32)
         sz = np.asarray([st.sizes[u[1]] for u in rec["uploads"]], np.float32)
         tau = np.asarray([r - u[2] for u in rec["uploads"]], np.float32)
-        g, w = agg(g, pool[idx], sz, tau)
+        g, w = step(g, pool, idx, sz, tau)
         out["weights"].append(np.asarray(w))
-        if r + 1 in st.kept_globals:
-            out["globals"][r + 1] = g
+        if r + 1 not in st.kept_globals:
+            continue
+        if against is None:
+            out["globals"][r + 1] = np.asarray(g)
+            continue
+        host = against[r + 1]
+        out["globals"][r + 1] = (
+            tuple(float(x) for x in widest(jax.device_put(host, g.sharding),
+                                           g))
+            if host.shape == g.shape else (float("inf"),) * 3)
     return out
 
 
-def program(st: State) -> dict:
-    return {"weights": [r["weights"] for r in st.rounds],
-            "globals": st.kept_globals}
-
-
-def gaps(got: dict, want: dict) -> dict:
+def gaps(got_weights: list, want_weights: list, globals_: dict,
+         scale: int) -> dict:
     """``global_gap``: the widest gap of a kept global, over the largest
-    magnitude of the reference's; ``weights_gap``: the widest gap of a
-    round's weights.  A shape that differs reads infinite."""
-    import jax.numpy as jnp
-
+    magnitude of the reference's (element ``scale`` of each reading);
+    ``weights_gap``: the widest gap of a round's weights.  A shape that
+    differs reads infinite."""
     w_gap = g_gap = 0.0
-    for a, b in zip(got["weights"], want["weights"]):
+    for a, b in zip(got_weights, want_weights):
         w_gap = max(w_gap, float(np.max(np.abs(a - b)))
                     if a.shape == b.shape else float("inf"))
-    if len(got["weights"]) != len(want["weights"]):
+    if len(got_weights) != len(want_weights):
         w_gap = float("inf")
-    for r, b in want["globals"].items():
-        a = got["globals"][r]
-        g_gap = max(g_gap, float(jnp.max(jnp.abs(a - b)) / jnp.max(jnp.abs(b)))
-                    if a.shape == b.shape else float("inf"))
+    for reading in globals_.values():
+        g_gap = max(g_gap, reading[0] / reading[scale])
     return {"global_gap": g_gap, "weights_gap": w_gap}
 
 
 def check(st: State, detail: bool = False) -> dict:
     import jax.numpy as jnp
 
-    return gaps(program(st), reference(st, jnp.float32))
+    want = reference(st, jnp.float32, against=st.kept_globals)
+    return gaps([r["weights"] for r in st.rounds], want["weights"],
+                want["globals"], scale=2)
 
 
 def control(st: State, detail: bool = False) -> dict:
@@ -276,4 +313,5 @@ def control(st: State, detail: bool = False) -> dict:
     import jax.numpy as jnp
 
     want = reference(st, jnp.float32)
-    return gaps(reference(st, jnp.bfloat16), want)
+    got = reference(st, jnp.bfloat16, against=want["globals"])
+    return gaps(got["weights"], want["weights"], got["globals"], scale=1)

@@ -15,6 +15,12 @@ reference, and prints one JSON line: ``correct``, ``attempted``,
 
 Cells, configurations, traffic mixes and metrics are found by the names
 in ``BENCHMARK.json``; see ``spec.py``.
+
+A cell on several chips runs its set-up, window, release and check inside
+the program's ``sharding.axis_rules`` over a one-axis ``pod`` mesh of
+exactly its chips, so the program places its state over them and the
+benchmark's own arrays follow (``weights.sharding``).  A one-chip cell gets
+no mesh and no rules: its programs are those of a plain one-device run.
 """
 from __future__ import annotations
 
@@ -23,6 +29,7 @@ import time
 T0 = time.perf_counter()
 
 import argparse  # noqa: E402
+import contextlib  # noqa: E402
 import gc  # noqa: E402
 import importlib  # noqa: E402
 import json  # noqa: E402
@@ -43,11 +50,14 @@ COMPILE_EVENTS = ("/jax/core/compile/backend_compile_duration",
 class Run:
     """What a metric's ``read(run)`` sees: the driver's counters and host
     spans (``c``), the trace reduction (``trace``, None with ``--trace
-    0``), the chip's peaks and the cell's files."""
+    0``), one chip's peaks, the cell's chips and the cell's files.  A share
+    of a peak over several chips passes ``chips`` to
+    ``costs.least_seconds``."""
 
     def __init__(self, cell, config, traffic, counters, trace, peaks):
         self.cell, self.config, self.traffic = cell, config, traffic
         self.c, self.trace, self.peaks = counters, trace, peaks
+        self.chips = cell["chips"]
 
 
 class CompileCounter:
@@ -67,6 +77,31 @@ class CompileCounter:
     def _event(self, name, **_kw):
         if self.on and name == COMPILE_EVENTS[1]:
             self.n += 1
+
+
+def pod_mesh(devs):
+    """The program's one-axis ``pod`` mesh over exactly ``devs``, with
+    automatic axes: the program slices and joins its flat vectors eagerly,
+    which explicit sharding types refuse."""
+    from jax.sharding import Mesh
+
+    from repro.launch.mesh import make_mesh
+
+    mesh = make_mesh((len(devs),), ("pod",))
+    if set(mesh.devices.flat) != set(devs):
+        raise RuntimeError(f"the pod mesh holds {list(mesh.devices.flat)}, "
+                           f"the cell's chips are {list(devs)}")
+    return Mesh(mesh.devices, mesh.axis_names)
+
+
+def placement(devs):
+    """The context a cell runs in: the program's axis rules over a pod mesh
+    of ``devs`` on several chips, nothing on one."""
+    if len(devs) == 1:
+        return contextlib.nullcontext()
+    from repro.sharding import axis_rules
+
+    return axis_rules(pod_mesh(devs))
 
 
 def driver_of(traffic: dict):
@@ -102,32 +137,34 @@ def run(workload: str, seed: int, seconds: float, trace_on: bool, *,
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     counter = CompileCounter()
     drv = driver_of(traffic)
-    st = drv.setup(config, traffic, seed)
-    setup_s = time.perf_counter() - t0
-    counter.on = True
-    reduced = None
-    if trace_on:
-        out: dict = {}
-        try:
-            with tr.capture(out):
-                with TraceAnnotation(tr.WINDOW_SPAN):
-                    res = drv.window(st, min(seconds,
-                                             traffic["trace_seconds"]))
-            t_red = time.perf_counter()
-            reduced = tr.Trace(tr.load(out["path"]))
-            print(f"[chipbench] trace read in "
-                  f"{time.perf_counter() - t_red:.1f} s", file=sys.stderr)
-        finally:
-            tr.discard(out)
-    else:
-        res = drv.window(st, seconds)
-    counter.on = False
-    res.update(setup_s=setup_s, memory_peak_bytes=device.peak_bytes(devs),
-               window_compiles=counter.n)
-    dev = device.line(devs)
-    drv.release(st)
-    gc.collect()
-    compared = drv.check(st)
+    with placement(devs):
+        st = drv.setup(config, traffic, seed)
+        setup_s = time.perf_counter() - t0
+        counter.on = True
+        reduced = None
+        if trace_on:
+            out: dict = {}
+            try:
+                with tr.capture(out):
+                    with TraceAnnotation(tr.WINDOW_SPAN):
+                        res = drv.window(st, min(seconds,
+                                                 traffic["trace_seconds"]))
+                t_red = time.perf_counter()
+                reduced = tr.Trace(tr.load(out["path"]))
+                print(f"[chipbench] trace read in "
+                      f"{time.perf_counter() - t_red:.1f} s", file=sys.stderr)
+            finally:
+                tr.discard(out)
+        else:
+            res = drv.window(st, seconds)
+        counter.on = False
+        res.update(setup_s=setup_s,
+                   memory_peak_bytes=device.peak_bytes(devs),
+                   window_compiles=counter.n)
+        dev = device.line(devs)
+        drv.release(st)
+        gc.collect()
+        compared = drv.check(st)
     correct = all(compared[k] <= limits[k] for k in compared)
     r = Run(cell, config, traffic, res, reduced, peaks)
     kind = "per_layer" if trace_on else "end_to_end"

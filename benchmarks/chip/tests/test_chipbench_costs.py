@@ -34,6 +34,18 @@ def test_least_seconds_takes_the_binding_roof():
     assert costs.least_seconds(10, 5000, pk) == 5.0       # FLOPs bind
 
 
+@pytest.mark.parametrize("chips", [1, 4])
+def test_least_seconds_spreads_the_work_over_the_chips(chips):
+    """The same work on four chips takes a quarter of the least time, so a
+    share of the peak reads the same whatever number of chips does it; one
+    chip reads exactly what it read before ``chips`` existed."""
+    pk = {"hbm_bw": 819e9, "flops_bf16": 197e12}
+    one = max(2.26e9 / pk["hbm_bw"], 6.2e8 / pk["flops_bf16"])
+    got = costs.least_seconds(2.26e9, 6.2e8, pk, chips)
+    assert got == (one if chips == 1 else pytest.approx(one / chips))
+    assert costs.least_seconds(2.26e9, 6.2e8, pk) == one
+
+
 def test_conv_flops():
     assert costs.conv_flops(4, 4, 3, 3, 2, 5) == 2 * 16 * 9 * 2 * 5
 
